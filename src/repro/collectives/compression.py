@@ -16,7 +16,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from ..models import sharding as sh
 
@@ -57,10 +56,10 @@ def compressed_psum_pod(grads, method: str = "bf16", residual=None):
         # over pod, GSPMD keeps per-pod partials only if we ask; here we
         # assume the caller passes per-pod partial grads sharded P() within
         # pod and performs the cross-pod sum here.
-        return shard_map(inner, mesh=mesh,
+        return jax.shard_map(inner, mesh=mesh,
                          in_specs=P(*(None,) * g.ndim),
                          out_specs=P(*(None,) * g.ndim),
-                         check_rep=False)(g)
+                         check_vma=False)(g)
 
     out = jax.tree_util.tree_map(reduce_leaf, grads)
     if residual is not None:

@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 # ---------------------------------------------------------------------------
@@ -129,8 +128,8 @@ def all_gather(x, mesh: Mesh, axis: str, impl: str = "rotation"):
             return jax.lax.all_gather(xl, axis, axis=0, tiled=True)
         return ring_all_gather(xl, axis, n)
 
-    return shard_map(inner, mesh=mesh, in_specs=P(axis),
-                     out_specs=P(), check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(axis),
+                     out_specs=P(), check_vma=False)(x)
 
 
 def all_reduce(x, mesh: Mesh, axis: str, impl: str = "rotation"):
@@ -144,8 +143,8 @@ def all_reduce(x, mesh: Mesh, axis: str, impl: str = "rotation"):
             return jax.lax.psum(xl, axis)
         return ring_all_reduce(xl, axis, n)
 
-    return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(),
+                     check_vma=False)(x)
 
 
 def reduce_scatter(x, mesh: Mesh, axis: str, impl: str = "rotation"):
@@ -157,8 +156,8 @@ def reduce_scatter(x, mesh: Mesh, axis: str, impl: str = "rotation"):
                                         tiled=True)
         return ring_reduce_scatter(xl, axis, n)
 
-    return shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(axis),
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(), out_specs=P(axis),
+                     check_vma=False)(x)
 
 
 def all_to_all(x, mesh: Mesh, axis: str, impl: str = "rotation"):
@@ -171,5 +170,5 @@ def all_to_all(x, mesh: Mesh, axis: str, impl: str = "rotation"):
                                       tiled=True)
         return rotation_all_to_all(xl, axis, n, split=0, concat=0)
 
-    return shard_map(inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                     check_vma=False)(x)

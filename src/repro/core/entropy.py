@@ -129,7 +129,9 @@ def draw_int(seed_lo, seed_hi, site, ids, slot, bound, lane=0):
 def draw_uniform(seed_lo, seed_hi, site, ids, slot, lane=0):
     """float32 uniforms in ``[0, 1)`` (24-bit mantissa resolution)."""
     u = draw_u32(seed_lo, seed_hi, site, ids, slot, lane=lane)
-    return (u >> np.uint32(8)).astype(np.float32) * _INV_2_24
+    # Through int32 (exact: the value is < 2**24): the TPU kernel compiler
+    # has no uint32 -> float32 conversion.
+    return (u >> np.uint32(8)).astype(np.int32).astype(np.float32) * _INV_2_24
 
 
 def uniform_grid(seed: int, site: int, n_ids: int, n_slots: int,

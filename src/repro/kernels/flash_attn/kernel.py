@@ -69,9 +69,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, block_k, causal,
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
                                              "interpret", "scale"))
-def flash_attention(q, k, v, *, causal: bool = True, scale=None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
+                    scale=None, block_q: int = 128, block_k: int = 128):
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D). Returns (B, Hq, Sq, D).
 
     For decode (Sq < block_q) the q tile shrinks to Sq.  Queries are assumed

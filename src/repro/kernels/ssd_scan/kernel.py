@@ -49,11 +49,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, *, chunk, n_state):
         y_intra = S @ xq                        # (Q, P)
         y_inter = jnp.exp(lam)[:, None] * (Cq @ h)
         o_slice = (y_intra + y_inter).astype(o_ref.dtype)
-        # scalar leading indices must be traced values: python ints break the
-        # interpret-mode state-discharge rule on jax 0.4.x
-        zero = jnp.int32(0)
-        pl.store(o_ref, (zero, zero, pl.dslice(sl, Q), pl.dslice(0, P)),
-                 o_slice)
+        o_ref[0, 0, pl.ds(sl, Q), :] = o_slice
         w = jnp.exp(lam[-1] - lam) * dq         # (Q,)
         h_new = jnp.exp(lam[-1]) * h + (Bq * w[:, None]).T @ xq
         return h_new
@@ -63,7 +59,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, *, chunk, n_state):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B_mat, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B_mat, C, *, interpret: bool, chunk: int = 64):
     """x (B,L,H,P); dt (B,L,H); A (H,); B_mat/C (B,L,G,N). Returns (B,L,H,P).
 
     L must be a multiple of ``chunk`` (the ops wrapper pads).

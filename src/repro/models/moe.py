@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import layers as L
 from . import sharding as sh
@@ -244,11 +243,11 @@ def moe_block(cfg, p, x, *, impl: Optional[str] = None):
             out = full[:Tfull]
         return out.reshape(Bl, Sl, D)
 
-    y = shard_map(
+    y = jax.shard_map(
         inner,
         mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec, w_spec, wd_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y + y_shared

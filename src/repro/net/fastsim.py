@@ -97,7 +97,7 @@ def segmented_cummax(v, seg_start, backend: str = "auto"):
         return _segmented_cummax_ref(v, seg_start)
     if backend == "pallas":
         from ..kernels.lindley import ops as _lops
-        return _lops.segmented_cummax(v, seg_start)
+        return _lops.segmented_cummax(v, seg_start, backend="pallas")
     raise ValueError(backend)
 
 
@@ -1010,11 +1010,13 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
     if batch == "mega":
         fn = jax.vmap(pipeline, in_axes=(0,) * n_args)
         if n_shards > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh, PartitionSpec
             mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("b",))
-            fn = shard_map(fn, mesh=mesh, in_specs=PartitionSpec("b"),
-                           out_specs=PartitionSpec("b"))
+            # check_vma=False: every operand and output is sharded on the
+            # fused axis, and the JSQ scan's replicated initial carry would
+            # otherwise be refused as not varying over it.
+            fn = jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec("b"),
+                               out_specs=PartitionSpec("b"), check_vma=False)
         jitted = jax.jit(fn)
     elif batch:                       # "seed" (True kept for back-compat)
         in_axes = (None,) * _N_STATIC + (0,) * (n_args - _N_STATIC)

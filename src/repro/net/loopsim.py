@@ -856,13 +856,12 @@ def _compiled(static: _Static, shapes: tuple, batch, n_shards: int):
     if batch == "mega":
         f = jax.vmap(fn, in_axes=(0,) * len(_ARG_ORDER))
         if n_shards > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import Mesh, PartitionSpec
             mesh = Mesh(np.asarray(jax.devices()[:n_shards]), ("b",))
-            # check_rep=False: shard_map has no replication rule for the
-            # while_loop primitive; every operand/output is sharded anyway.
-            f = shard_map(f, mesh=mesh, in_specs=PartitionSpec("b"),
-                          out_specs=PartitionSpec("b"), check_rep=False)
+            # check_vma=False: every operand and output is sharded on the
+            # fused axis, so there is no replication to track.
+            f = jax.shard_map(f, mesh=mesh, in_specs=PartitionSpec("b"),
+                              out_specs=PartitionSpec("b"), check_vma=False)
         return jax.jit(f)
     if batch == "seed":
         in_axes = tuple(0 if k in _SEED_KEYS else None for k in _ARG_ORDER)

@@ -20,7 +20,6 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import pathlib
 import sys
 
@@ -99,18 +98,12 @@ def cmd_run(args) -> int:
     level = "quiet" if quiet else ("debug" if args.verbose else "info")
     trace = TraceWriter(out / "trace.jsonl" if out else None,
                         overwrite=not resume)
-    # Precedence: --no-compile-cache > --compile-cache > $REPRO_COMPILE_CACHE
-    # (resolved inside compile_cache.enable) > <out>/jax-cache.
-    if args.no_compile_cache:
-        cache_dir = False
-    elif args.compile_cache:
-        cache_dir = args.compile_cache
-    elif os.environ.get(compile_cache.ENV_VAR):
-        cache_dir = None
-    else:
-        cache_dir = str(out / "jax-cache") if out else None
+    # --no-compile-cache > $JAX_COMPILATION_CACHE_DIR (resolved inside
+    # compile_cache.enable) > the fixed <checkout>/jax-cache.
     run_campaign(
-        c, store=store, compile_cache_dir=cache_dir,
+        c, store=store,
+        compile_cache_dir=(False if args.no_compile_cache
+                           else compile_cache.DEFAULT_DIR),
         trace=trace, log=SweepLogger(level),
         timing_split=args.timing_split, profile_dir=args.profile,
         retry=args.retry, backoff_s=args.backoff, resume=resume,
@@ -129,6 +122,14 @@ def cmd_run(args) -> int:
         if out:
             print(f"wrote {out / 'results.jsonl'}, {out / 'summary.jsonl'} "
                   f"and {out / 'trace.jsonl'}")
+    # The runner degrades past failed dispatches and records what survived;
+    # a campaign that lost points is still a failed run.
+    n_points = plan(c).n_points
+    missing = n_points - len(store.records)
+    if missing:
+        print(f"error: {missing} of {n_points} grid points produced no "
+              f"record (see the error spans in the trace)", file=sys.stderr)
+        return 1
     return 0
 
 
@@ -215,11 +216,10 @@ def main(argv=None) -> int:
     _spec_args(p_run)
     p_run.add_argument("--out", help="output dir for results/summary/trace "
                                      "JSONL")
-    p_run.add_argument("--compile-cache", metavar="DIR",
-                       help="persistent JAX compile cache directory "
-                            "(default: <out>/jax-cache, or "
-                            "$REPRO_COMPILE_CACHE)")
-    p_run.add_argument("--no-compile-cache", action="store_true")
+    p_run.add_argument("--no-compile-cache", action="store_true",
+                       help="run without the persistent compile cache "
+                            "(default: $JAX_COMPILATION_CACHE_DIR, else "
+                            "<checkout>/jax-cache)")
     p_run.add_argument("--quiet", action="store_true",
                        help="no progress output")
     p_run.add_argument("--verbose", "-v", action="store_true",

@@ -16,8 +16,9 @@ workloads and failure states across batches, and executes
 Each grid point yields one record in the :class:`~repro.sweep.results
 .ResultStore`; per-point results are bitwise-identical to standalone
 ``fastsim.simulate`` calls with the same seeds (tested in
-``tests/test_sweep.py``).  Pass ``compile_cache=<dir>`` (or set
-``REPRO_COMPILE_CACHE``) to persist compiled pipelines across invocations.
+``tests/test_sweep.py``).  Pass ``compile_cache_dir=<dir>`` (or set
+``JAX_COMPILATION_CACHE_DIR``) to persist compiled pipelines across
+invocations.
 
 Telemetry (``repro.obs``): every run can emit a versioned JSONL dispatch
 trace (``trace=TraceWriter(...)``) -- one span per fused dispatch carrying
@@ -365,10 +366,12 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
     ``store`` when given, in grid-plan order).  ``full_results`` maps
     ``GridPoint -> FastSimResult/LoopSimResult`` when ``keep_full=True``
     (tests and figure code that need raw delivery vectors), else ``{}``.
-    ``compile_cache_dir`` (or the ``REPRO_COMPILE_CACHE`` env var) enables
-    the persistent JAX compilation cache, so repeat invocations skip
-    compiles entirely; pass ``False`` to keep it off even when the env var
-    is set.
+    ``compile_cache_dir`` enables the persistent JAX compilation cache, so
+    repeat invocations skip compiles entirely; ``JAX_COMPILATION_CACHE_DIR``
+    takes precedence over it when set (see :mod:`.compile_cache`).  Pass
+    ``False`` to turn the cache off even when the env var is set; that
+    switches JAX's persistent cache off for the whole process, and it stays
+    off after the campaign returns until a later call enables it.
 
     Observability (all optional, all pure observers):
 
@@ -382,8 +385,7 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
       compile caches and returns identical results) and report
       ``compile_s`` / ``execute_s`` separately in the trace.
     * ``profile_dir`` -- wrap execution in ``jax.profiler.trace`` for
-      TensorBoard-grade timelines (skipped with a log line if the profiler
-      is unavailable on this backend).
+      TensorBoard-grade timelines (a backend without a profiler raises).
 
     Robustness:
 
@@ -392,7 +394,9 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
       exponential backoff ``backoff_s * 2**attempt`` before degrading:
       whole megabatch -> per-member dispatches -> serial per-point.  Points
       that fail terminally yield error spans instead of records; the
-      campaign keeps going.  ``sleep`` is injectable for tests.
+      campaign keeps going, so callers that need every point compare the
+      record count with the plan (the CLI exits non-zero when points are
+      missing).  ``sleep`` is injectable for tests.
     * ``resume`` -- treat ``store``'s existing records as a checkpoint:
       dispatches whose full record block is already present are skipped,
       a partially-recorded dispatch is truncated off and re-run whole.
@@ -408,8 +412,11 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
     if log is None:
         log = (SweepLogger("debug", sink=progress) if progress is not None
                else SweepLogger("quiet"))
-    cache_dir = (None if compile_cache_dir is False
-                 else compile_cache.enable(compile_cache_dir))
+    if compile_cache_dir is False:
+        compile_cache.disable()
+        cache_dir = None
+    else:
+        cache_dir = compile_cache.enable(compile_cache_dir)
     import jax
     devices = len(jax.devices())
     p = plan(campaign, cost_params=cost_params)
@@ -482,12 +489,8 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
         log.info(f"resume: {done}/{p.n_dispatches} dispatches already "
                  f"complete ({len(store.records)} records kept)")
 
-    prof = contextlib.nullcontext()
-    if profile_dir:
-        try:
-            prof = jax.profiler.trace(str(profile_dir))
-        except Exception as e:          # profiler missing on this backend
-            log.info(f"jax.profiler unavailable ({e}); profiling skipped")
+    prof = (jax.profiler.trace(str(profile_dir)) if profile_dir
+            else contextlib.nullcontext())
 
     cache_files0 = _cache_files(cache_dir)
     real_rows = padded_rows = 0     # realized padded-row fill this run

@@ -26,7 +26,7 @@ def test_lindley_kernel_matches_oracle(n, block, rng):
     f = rng.random(n) < 0.15
     f[0] = True
     out_k = np.asarray(lk.segmented_cummax(jnp.asarray(v), jnp.asarray(f),
-                                           block=block))
+                                           block=block, interpret=True))
     out_r = np.asarray(lr.segmented_cummax(jnp.asarray(v), jnp.asarray(f)))
     np.testing.assert_allclose(out_k, out_r)
 
@@ -82,7 +82,8 @@ def test_flash_attention_matches_ref(shape, dtype, rng):
     q = jnp.asarray(rng.normal(size=(B, Hq, Sq, D)), dtype)
     k = jnp.asarray(rng.normal(size=(B, Hkv, Sk, D)), dtype)
     v = jnp.asarray(rng.normal(size=(B, Hkv, Sk, D)), dtype)
-    out_k = fk.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    out_k = fk.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                               interpret=True)
     out_r = fr.mha(q, k, v, causal=True)
     tol = 2e-5 if dtype == np.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out_k, np.float32),
@@ -157,7 +158,8 @@ def test_ssd_kernel_and_chunked_match_scan(shape, rng):
                                 backend="chunked"))
     np.testing.assert_allclose(chunked, oracle, atol=5e-5, rtol=5e-4)
     if L % 32 == 0:
-        pallas = np.asarray(sk.ssd_scan(x, dt, A, Bm, C, chunk=32))
+        pallas = np.asarray(sk.ssd_scan(x, dt, A, Bm, C, chunk=32,
+                                        interpret=True))
         np.testing.assert_allclose(pallas, oracle, atol=5e-5, rtol=5e-4)
 
 

@@ -198,6 +198,19 @@ def test_megabatch_sharded_bitwise_identical(tree, perm_wl, two_devices):
             _assert_bitwise_equal(res, fastsim.simulate(t, w, s_, seed=seed))
 
 
+def test_megabatch_sharded_jsq_bitwise_identical(tree, perm_wl, two_devices):
+    """The JSQ pipeline, whose per-switch scan starts from a replicated
+    carry, shards too.  Called directly (no runner ladder to degrade
+    through), a sharding fault raises here instead of being retried
+    serially."""
+    sch = lbs.by_name("switch_pkt_ar")
+    sharded, = fastsim.simulate_megabatch(
+        [(tree, perm_wl, sch, [0, 1, 2], None)], n_shards="auto")
+    for seed, res in zip([0, 1, 2], sharded):
+        _assert_bitwise_equal(res, fastsim.simulate(tree, perm_wl, sch,
+                                                    seed=seed))
+
+
 def test_padding_preserves_delivered_packet_counts(tree):
     """Shape-bucketing pad packets are inert: per-layer delivered-packet
     counts match the unpadded run exactly."""
@@ -348,7 +361,17 @@ def test_loop_campaign_matches_standalone_simulate(tree, perm_wl):
         assert res.retransmissions == ref.retransmissions
 
 
-def test_compile_cache_persists_executables(tmp_path):
+@pytest.fixture
+def cache_env(monkeypatch):
+    """No JAX_COMPILATION_CACHE_DIR from the caller's environment; the
+    process-wide cache is switched off again after the test."""
+    from repro.sweep import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    yield monkeypatch
+    compile_cache.disable()
+
+
+def test_compile_cache_persists_executables(tmp_path, cache_env):
     cache_dir = tmp_path / "jax-cache"
     # Drop in-process compile reuse so the dispatch actually compiles (and
     # therefore writes a persistent entry) inside this test.
@@ -357,6 +380,99 @@ def test_compile_cache_persists_executables(tmp_path):
                        compile_cache_dir=str(cache_dir))
     entries = list(cache_dir.iterdir())
     assert entries, "persistent compile cache left no entries"
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_dir_precedence(tmp_path, cache_env, env_set):
+    """JAX_COMPILATION_CACHE_DIR wins over the caller's path; without it
+    the caller's path is used, and nothing is set up without either."""
+    from repro.sweep import compile_cache
+    asked, env_dir = tmp_path / "asked", tmp_path / "env"
+    if env_set:
+        cache_env.setenv(compile_cache.ENV_VAR, str(env_dir))
+    want = env_dir if env_set else asked
+    assert compile_cache.enable(str(asked)) == str(want)
+    assert want.is_dir() and not (env_set and asked.exists())
+    assert compile_cache.active_dir() == str(want)
+    assert compile_cache.resolve(None) == (str(env_dir) if env_set
+                                           else None)
+
+
+def test_compile_cache_off_is_process_wide(tmp_path, cache_env):
+    """``compile_cache_dir=False`` switches JAX's persistent cache off for
+    the process, as documented, and it stays off after the campaign."""
+    import jax
+    from repro.sweep import compile_cache
+    compile_cache.enable(str(tmp_path / "jax-cache"))
+    sweep.run_campaign(_campaign(seeds=(0,), schemes=("host_pkt",)),
+                       compile_cache_dir=False)
+    assert compile_cache.active_dir() is None
+    assert not jax.config.jax_enable_compilation_cache
+
+
+def test_compile_cache_default_is_fixed_checkout_path():
+    import pathlib
+    from repro.sweep import compile_cache
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert pathlib.Path(compile_cache.DEFAULT_DIR) == root / "jax-cache"
+
+
+@pytest.mark.parametrize("flag", [[], ["--no-compile-cache"]])
+def test_cli_compile_cache_choice(tmp_path, monkeypatch, flag):
+    """The CLI asks for the fixed checkout cache, never <out>/jax-cache;
+    --no-compile-cache asks for none."""
+    from repro.sweep import __main__ as cli, compile_cache
+    seen = {}
+
+    def fake_run(c, **kw):
+        seen.update(kw)
+        return [], {}
+
+    monkeypatch.setattr(cli, "run_campaign", fake_run)
+    cli.main(["run", "--preset", "table2", "--out", str(tmp_path / "out"),
+              "--quiet", *flag])
+    want = False if flag else compile_cache.DEFAULT_DIR
+    assert seen["compile_cache_dir"] == want
+
+
+def test_compile_cache_unusable_dir_raises(tmp_path, cache_env):
+    from repro.sweep import compile_cache
+    blocker = tmp_path / "not-a-dir"
+    blocker.write_text("")
+    with pytest.raises(OSError):
+        compile_cache.enable(str(blocker))
+
+
+@pytest.mark.parametrize("poisoned", [False, True])
+def test_cli_exit_code_counts_lost_points(tmp_path, monkeypatch, poisoned):
+    """``run`` exits non-zero when any planned point has no record, even
+    though the runner degraded past the failure instead of raising."""
+    from repro.sweep import __main__ as cli, runner
+
+    def always_raises(mega, campaign, cache):
+        raise RuntimeError("dispatch failed")
+
+    if poisoned:
+        monkeypatch.setattr(runner, "_run_fast_mega", always_raises)
+    spec = tmp_path / "spec.json"
+    spec.write_text(json.dumps(_campaign(seeds=(0,),
+                                         schemes=("host_pkt",)).to_dict()))
+    rc = cli.main(["run", "--spec", str(spec), "--out",
+                   str(tmp_path / "out"), "--quiet", "--no-compile-cache"])
+    assert rc == (1 if poisoned else 0)
+
+
+def test_profile_without_profiler_raises(tmp_path, monkeypatch):
+    import jax
+
+    def no_profiler(*a, **kw):
+        raise RuntimeError("no profiler on this backend")
+
+    monkeypatch.setattr(jax.profiler, "trace", no_profiler)
+    with pytest.raises(RuntimeError, match="no profiler"):
+        sweep.run_campaign(_campaign(seeds=(0,), schemes=("host_pkt",)),
+                           compile_cache_dir=False,
+                           profile_dir=str(tmp_path / "prof"))
 
 
 def test_cross_k_grid_one_dispatch_per_engine():
