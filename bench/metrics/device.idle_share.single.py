@@ -1,0 +1,11 @@
+"""Share of the traced window in which no operation ran on the device, in
+single-point campaigns: 1 - (union of device operation intervals) /
+window, from the profiler trace.  Moves ``point_p95_s``: each point waits
+for the host work that keeps the device idle."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or tr["window_s"] <= 0 or tr["busy_s"] <= 0:
+        return None
+    return 1.0 - tr["busy_s"] / tr["window_s"]
