@@ -74,6 +74,7 @@ from ..core.lb_schemes import LBScheme, precompute_host_choices
 from ..core import entropy as ent
 from ..core import ofan as ofan_mod
 from ..obs.probes import QueueProbe, probe_shape
+from ..obs.stages import count, execute, fetch, scopes, stage
 
 _NEG = -1.0e9
 
@@ -739,93 +740,103 @@ def simulate_megabatch(items, *, prop_slots: float = 12.0,
     if not items or all(not it[3] for it in items):
         return [[] for _ in items]
 
-    plans = [_prepare(tree, wl, scheme, prop_slots, links, backend,
-                      jsq_pad_factor, fault=fz)
-             for (tree, wl, scheme, _, links, fz) in items]
-    idents = {_pipeline_identity(p) for p in plans}
-    if len(idents) > 1:
-        raise ValueError(f"megabatch items span {len(idents)} pipeline "
-                         f"identities; group by LBScheme.shape_key() first")
+    with stage("prep"):
+        plans = [_prepare(tree, wl, scheme, prop_slots, links, backend,
+                          jsq_pad_factor, fault=fz)
+                 for (tree, wl, scheme, _, links, fz) in items]
+        idents = {_pipeline_identity(p) for p in plans}
+        if len(idents) > 1:
+            raise ValueError(f"megabatch items span {len(idents)} "
+                             f"pipeline identities; group by "
+                             f"LBScheme.shape_key() first")
 
-    k_max = max(p.tree.k for p in plans)
-    k_pad = k_max if k_pad is None else max(int(k_pad), k_max)
-    tree_pad = next((p.tree for p in plans if p.tree.k == k_pad),
-                    FatTree(k_pad))
-    pads = [TreePad(p.tree, tree_pad) for p in plans]
+        k_max = max(p.tree.k for p in plans)
+        k_pad = k_max if k_pad is None else max(int(k_pad), k_max)
+        tree_pad = next((p.tree for p in plans if p.tree.k == k_pad),
+                        FatTree(k_pad))
+        pads = [TreePad(p.tree, tree_pad) for p in plans]
 
-    npk_max = max(p.wl.n_packets for p in plans)
-    npk_pad = npk_max if npk_pad is None else max(int(npk_pad), npk_max)
-    pad_e_m = max(p.pad_e for p in plans)
-    pad_a_m = max(p.pad_a for p in plans)
-    jsq = plans[0].jsq
+        npk_max = max(p.wl.n_packets for p in plans)
+        npk_pad = (npk_max if npk_pad is None
+                   else max(int(npk_pad), npk_max))
+        pad_e_m = max(p.pad_e for p in plans)
+        pad_a_m = max(p.pad_a for p in plans)
+        jsq = plans[0].jsq
 
-    elems: list = []          # merged (static + per-seed) dicts, padded
-    spans: list = []          # (item index, seed) per fused-axis element
-    for i, ((tree, wl, scheme, seeds, links, fz), plan) in enumerate(
-            zip(items, plans)):
-        for s in seeds:
-            d = _repad_elem({**plan.static_args,
-                             **_draw_seed_inputs(plan, s)}, plan, pads[i])
-            for k in _PKT_KEYS:
-                d[k] = _pad_tail(d[k], 0, npk_pad,
-                                 fill=-1 if k == "dst" else 0)
-            if jsq:
-                d["noise_e"] = _pad_tail(d["noise_e"], 1, pad_e_m)
-                d["noise_a"] = _pad_tail(d["noise_a"], 1, pad_a_m)
-            elems.append(d)
-            spans.append((i, s))
+        elems: list = []          # merged (static + per-seed) dicts, padded
+        spans: list = []          # (item index, seed) per fused-axis element
+        for i, ((tree, wl, scheme, seeds, links, fz), plan) in enumerate(
+                zip(items, plans)):
+            for s in seeds:
+                d = _repad_elem({**plan.static_args,
+                                 **_draw_seed_inputs(plan, s)}, plan,
+                                pads[i])
+                for k in _PKT_KEYS:
+                    d[k] = _pad_tail(d[k], 0, npk_pad,
+                                     fill=-1 if k == "dst" else 0)
+                if jsq:
+                    d["noise_e"] = _pad_tail(d["noise_e"], 1, pad_e_m)
+                    d["noise_a"] = _pad_tail(d["noise_a"], 1, pad_a_m)
+                elems.append(d)
+                spans.append((i, s))
 
-    # Scheme tables (RR permutation epochs, OFAN rotation orders) are padded
-    # per-position to the group-wide maximum shape; padded entries are only
-    # ever indexed by inert packets, whose outputs are discarded.
-    for key in ("te", "ta"):
-        for j in range(len(elems[0][key])):
-            padded = pad_to_group_max([d[key][j] for d in elems])
-            for d, t in zip(elems, padded):
-                d[key] = d[key][:j] + (t,) + d[key][j + 1:]
+        # Scheme tables (RR permutation epochs, OFAN rotation orders) are
+        # padded per-position to the group-wide maximum shape; padded entries
+        # are only ever indexed by inert packets, whose outputs are
+        # discarded.
+        for key in ("te", "ta"):
+            for j in range(len(elems[0][key])):
+                padded = pad_to_group_max([d[key][j] for d in elems])
+                for d, t in zip(elems, padded):
+                    d[key] = d[key][:j] + (t,) + d[key][j + 1:]
 
-    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *elems)
+        stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *elems)
 
-    n_batch = len(elems)
-    if n_shards == "auto":
-        n_shards = max(1, min(len(jax.devices()), n_batch))
-    n_shards = int(n_shards)
-    stacked = shard_pad(stacked, n_batch, n_shards)
+        n_batch = len(elems)
+        if n_shards == "auto":
+            n_shards = max(1, min(len(jax.devices()), n_batch))
+        n_shards = int(n_shards)
+        stacked = shard_pad(stacked, n_batch, n_shards)
 
-    run = plans[0].build_run("mega", pad_e=pad_e_m, pad_a=pad_a_m,
-                             n_shards=n_shards, tree=tree_pad, probes=probes)
-    out = run(stacked)
-    out = jax.tree_util.tree_map(np.asarray, out)
+        run = plans[0].build_run("mega", pad_e=pad_e_m, pad_a=pad_a_m,
+                                 n_shards=n_shards, tree=tree_pad,
+                                 probes=probes)
+    out = fetch(execute(run, stacked))
 
     results = [dict() for _ in items]
     retries: Dict[int, list] = {}
-    for b, (i, s) in enumerate(spans):
-        if bool(out["overflow"][b]):
-            retries.setdefault(i, []).append(s)
-            continue
-        out_b = jax.tree_util.tree_map(lambda x: x[b], out)
-        npk_i = plans[i].wl.n_packets
-        for k in ("delivery", "a_used", "c_used"):
-            out_b[k] = out_b[k][:npk_i]
-        out_b["occ"] = out_b["occ"][:, :npk_i]
-        if not pads[i].noop:
-            # Gather per-queue packet counts back onto the real tree's queue
-            # ids (padded queues hold zero: no real packet ever lands there).
-            out_b["counts"] = ([c[pads[i].mid] for c in out_b["counts"][:4]]
-                               + [out_b["counts"][4][:plans[i].tree.n_hosts]])
-        results[i][s] = _postprocess(out_b, plans[i].wl, probes)
+    with stage("post"):
+        for b, (i, s) in enumerate(spans):
+            if bool(out["overflow"][b]):
+                retries.setdefault(i, []).append(s)
+                continue
+            out_b = jax.tree_util.tree_map(lambda x: x[b], out)
+            npk_i = plans[i].wl.n_packets
+            for k in ("delivery", "a_used", "c_used"):
+                out_b[k] = out_b[k][:npk_i]
+            out_b["occ"] = out_b["occ"][:, :npk_i]
+            if not pads[i].noop:
+                # Gather per-queue packet counts back onto the real tree's
+                # queue ids (padded queues hold zero: no real packet ever
+                # lands there).
+                cnt = out_b["counts"]
+                out_b["counts"] = ([c[pads[i].mid] for c in cnt[:4]]
+                                   + [cnt[4][:plans[i].tree.n_hosts]])
+            results[i][s] = _postprocess(out_b, plans[i].wl, probes)
 
     # JSQ pad overflow: re-run exactly the (item, seed) cells a standalone
     # run would re-pad, through the seed-batched path (whose retry is itself
     # bitwise-identical to serial simulate).
     for i, retry_seeds in retries.items():
-        tree, wl, scheme, _, links, fz = items[i]
-        redone = simulate_batch(tree, wl, scheme, retry_seeds,
-                                prop_slots=prop_slots, links=links,
-                                backend=backend,
-                                jsq_pad_factor=jsq_pad_factor * 2,
-                                probes=probes, fault=fz)
-        results[i].update(dict(zip(retry_seeds, redone)))
+        with stage("jsq_retry", key="retry"):
+            count("jsq_retries", len(retry_seeds))
+            tree, wl, scheme, _, links, fz = items[i]
+            redone = simulate_batch(tree, wl, scheme, retry_seeds,
+                                    prop_slots=prop_slots, links=links,
+                                    backend=backend,
+                                    jsq_pad_factor=jsq_pad_factor * 2,
+                                    probes=probes, fault=fz)
+            results[i].update(dict(zip(retry_seeds, redone)))
 
     return [[results[i][s] for s in seeds]
             for i, (_, _, _, seeds, _, _) in enumerate(items)]
@@ -868,9 +879,9 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
 
     mid = n_pods * h * h   # queues per middle layer
 
-    def pipeline(p1, e1, p2, e2, dst, inter_pod, leaves_edge, ep_sw,
-                 pad_lim_e, pad_lim_a, h_log, t_rel, tie,
-                 a_pre, c_pre, rand_a, rand_c, noise_e, noise_a, te, ta):
+    def layers(at, p1, e1, p2, e2, dst, inter_pod, leaves_edge, ep_sw,
+               pad_lim_e, pad_lim_a, h_log, t_rel, tie,
+               a_pre, c_pre, rand_a, rand_c, noise_e, noise_a, te, ta):
         tbl_e = dict(zip(tables_e_keys, te))
         tbl_a = dict(zip(tables_a_keys, ta))
         if "rr_starts" in tbl_e:
@@ -887,6 +898,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         edge_switch = p1 * h + e1
 
         # ---------- UP_E ----------
+        at("up_e")
         if edge_mode == "pre":
             a_used = a_pre
         elif edge_mode == "rand":
@@ -923,6 +935,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         a_t = jnp.where(leaves_edge, d + prop, a_t)
 
         # ---------- UP_A ----------
+        at("up_a")
         agg_switch = p1 * h + a_used
         if agg_mode == "pre":
             c_used = c_pre
@@ -959,6 +972,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         a_t = jnp.where(inter_pod, d + prop, a_t)
 
         # ---------- DN_C (forced: core (a_used, c_used) -> agg a_used of p2) --
+        at("dn_c")
         qid = jnp.where(inter_pod, (p2 * h + a_used) * h + c_used, -1)
         d, cnt, occ = _lindley_layer(qid, a_t, tie, mid, backend)
         counts.append(cnt); occs.append(occ)
@@ -967,6 +981,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         a_t = jnp.where(inter_pod, d + prop, a_t)
 
         # ---------- DN_A (forced: agg a_used -> edge e2) ----------
+        at("dn_a")
         qid = jnp.where(leaves_edge, (p2 * h + a_used) * h + e2, -1)
         d, cnt, occ = _lindley_layer(qid, a_t, tie, mid, backend)
         counts.append(cnt); occs.append(occ)
@@ -975,6 +990,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         a_t = jnp.where(leaves_edge, d + prop, a_t)
 
         # ---------- DN_E (forced: edge -> host) ----------
+        at("dn_e")
         d, cnt, occ = _lindley_layer(dst, a_t, tie, n_hosts, backend)
         counts.append(cnt); occs.append(occ)
         # dst == -1 marks shape-bucketing pad packets (inert bypass rows);
@@ -982,6 +998,7 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
         n_real.append(jnp.sum(dst >= 0))
         p_arr.append(a_t); p_act.append(dst >= 0)
         delivery = d + prop
+        at(None)
 
         out = {"delivery": delivery,
                "counts": counts,
@@ -1006,6 +1023,12 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
             out["probe_q"] = qsr
         return out
 
+    def pipeline(*args):
+        # Each layer round's device operations carry its name (up_e ...
+        # dn_e) in their HLO metadata.
+        with scopes() as at:
+            return layers(at, *args)
+
     n_args = len(_ARG_ORDER)
     if batch == "mega":
         fn = jax.vmap(pipeline, in_axes=(0,) * n_args)
@@ -1027,4 +1050,5 @@ def _build_run(*, h, n_pods, n_edges, n_aggs, n_hosts, edge_mode, agg_mode,
     def run(kw: dict):
         return jitted(*(kw[k] for k in _ARG_ORDER))
 
+    run.jitted = jitted
     return run

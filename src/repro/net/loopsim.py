@@ -92,6 +92,7 @@ from ..core.lb_schemes import LBScheme, precompute_host_choices
 from ..core import entropy as ent
 from ..core import ofan as ofan_mod
 from ..obs.probes import QueueProbe, probe_shape
+from ..obs.stages import execute, fetch, scopes, stage
 
 INT = jnp.int32
 
@@ -694,101 +695,106 @@ def simulate_megabatch(items, *, npk_pad: Optional[int] = None,
     if not items or all(not it[4] for it in items):
         return [[] for _ in items]
 
-    plans = [_prepare(t, w, s, c, l, g, probes=probes, fault=fz)
-             for (t, w, s, c, _, l, g, fz) in items]
-    idents = {_pipeline_identity(p) for p in plans}
-    if len(idents) > 1:
-        raise ValueError(f"megabatch items span {len(idents)} pipeline "
-                         f"identities; group by tree size, scheme loop "
-                         f"shape and static LoopConfig first")
+    with stage("prep"):
+        plans = [_prepare(t, w, s, c, l, g, probes=probes, fault=fz)
+                 for (t, w, s, c, _, l, g, fz) in items]
+        idents = {_pipeline_identity(p) for p in plans}
+        if len(idents) > 1:
+            raise ValueError(f"megabatch items span {len(idents)} pipeline "
+                             f"identities; group by tree size, scheme loop "
+                             f"shape and static LoopConfig first")
 
-    k_max = max(p.tree.k for p in plans)
-    k_pad = k_max if k_pad is None else max(int(k_pad), k_max)
-    tree_pad = next((p.tree for p in plans if p.tree.k == k_pad),
-                    FatTree(k_pad))
-    pads = [TreePad(p.tree, tree_pad) for p in plans]
+        k_max = max(p.tree.k for p in plans)
+        k_pad = k_max if k_pad is None else max(int(k_pad), k_max)
+        tree_pad = next((p.tree for p in plans if p.tree.k == k_pad),
+                        FatTree(k_pad))
+        pads = [TreePad(p.tree, tree_pad) for p in plans]
 
-    P_max = max(p.wl.n_packets for p in plans)
-    # The engine's per-step packet gathers need a non-empty packet axis
-    # even when every member is degenerate (all-empty phase schedules).
-    npk_pad = max(P_max if npk_pad is None else max(int(npk_pad), P_max), 1)
-    F_pad = max(p.wl.n_flows for p in plans)
-    Fh_pad = max(p.static.Fh for p in plans)
-    E_pad = max(p.n_epochs for p in plans)
+        P_max = max(p.wl.n_packets for p in plans)
+        # The engine's per-step packet gathers need a non-empty packet axis
+        # even when every member is degenerate (all-empty phase schedules).
+        npk_pad = max(P_max if npk_pad is None
+                      else max(int(npk_pad), P_max), 1)
+        F_pad = max(p.wl.n_flows for p in plans)
+        Fh_pad = max(p.static.Fh for p in plans)
+        E_pad = max(p.n_epochs for p in plans)
 
-    elems: list = []          # merged (static + per-seed) dicts, padded
-    spans: list = []          # (item index, seed) per fused-axis element
-    for i, ((tree, wl, scheme, cfg, seeds, links, g, fz), plan) in enumerate(
-            zip(items, plans)):
-        st = _repad_tables(plan.tables, plan, pads[i])
-        # Fault-epoch padding: tables repeat their last real epoch; the
-        # start operands pad with an unreachable sentinel slot, so the
-        # epoch/reaction counters never index a pad epoch -- padded rows
-        # are bitwise-inert, letting static and flapping points fuse.
-        for k in ("alive", "e_ports", "e_pcnt", "a_ports", "a_pcnt",
-                  "e_dead", "a_dead", "f_vpaths", "f_vcnt"):
-            st[k] = _pad_epochs(st[k], E_pad)
-        for k in ("ep_start", "r_start"):
-            st[k] = pad_tail(st[k], 0, E_pad, fill=2**30)
-        # Flow-axis padding: pad flows have fsize 0, so they complete at the
-        # first slot, never send, and never reference a packet; pkt_base is
-        # edge-padded so searchsorted still lands real packets on real flows.
-        st["pkt_base"] = pad_tail(st["pkt_base"], 0, F_pad + 1,
-                                  fill=int(st["pkt_base"][-1]))
-        for k in _F_PAD0:
-            st[k] = pad_tail(st[k], 0, F_pad)
-        st["f_inter"] = pad_tail(st["f_inter"], 0, F_pad, fill=False)
-        st["f_leaves"] = pad_tail(st["f_leaves"], 0, F_pad, fill=False)
-        st["f_vpaths"] = pad_tail(st["f_vpaths"], 1, F_pad)
-        st["f_vcnt"] = pad_tail(st["f_vcnt"], 1, F_pad, fill=1)
-        # Padded host_flows columns hold -1 and rank below every real flow
-        # in the host round-robin, so picks (and hence all sends) match the
-        # unpadded point exactly.
-        st["host_flows"] = pad_tail(st["host_flows"], 1, Fh_pad, fill=-1)
-        for s in seeds:
-            d = {**st, **_repad_seed(_draw_seed_inputs(plan, s), plan,
-                                     pads[i])}
-            for k in ("a_stale", "c_stale"):
-                d[k] = pad_tail(d[k], 0, npk_pad)
-            for k in ("a_conv", "c_conv"):
-                d[k] = pad_tail(_pad_epochs(d[k], E_pad), 1, npk_pad)
-            # OFAN stacks lead with the [stale, epoch...] axis: 1 + E.
-            for k in ("ofan_e_orders", "ofan_e_starts", "ofan_e_len",
-                      "ofan_a_orders", "ofan_a_starts", "ofan_a_len"):
-                d[k] = _pad_epochs(d[k], 1 + E_pad)
-            elems.append(d)
-            spans.append((i, s))
+        elems: list = []          # merged (static + per-seed) dicts, padded
+        spans: list = []          # (item index, seed) per fused-axis element
+        for i, ((tree, wl, scheme, cfg, seeds, links, g, fz),
+                plan) in enumerate(zip(items, plans)):
+            st = _repad_tables(plan.tables, plan, pads[i])
+            # Fault-epoch padding: tables repeat their last real epoch; the
+            # start operands pad with an unreachable sentinel slot, so the
+            # epoch/reaction counters never index a pad epoch -- padded rows
+            # are bitwise-inert, letting static and flapping points fuse.
+            for k in ("alive", "e_ports", "e_pcnt", "a_ports", "a_pcnt",
+                      "e_dead", "a_dead", "f_vpaths", "f_vcnt"):
+                st[k] = _pad_epochs(st[k], E_pad)
+            for k in ("ep_start", "r_start"):
+                st[k] = pad_tail(st[k], 0, E_pad, fill=2**30)
+            # Flow-axis padding: pad flows have fsize 0, so they complete at
+            # the first slot, never send, and never reference a packet;
+            # pkt_base is edge-padded so searchsorted still lands real
+            # packets on real flows.
+            st["pkt_base"] = pad_tail(st["pkt_base"], 0, F_pad + 1,
+                                      fill=int(st["pkt_base"][-1]))
+            for k in _F_PAD0:
+                st[k] = pad_tail(st[k], 0, F_pad)
+            st["f_inter"] = pad_tail(st["f_inter"], 0, F_pad, fill=False)
+            st["f_leaves"] = pad_tail(st["f_leaves"], 0, F_pad, fill=False)
+            st["f_vpaths"] = pad_tail(st["f_vpaths"], 1, F_pad)
+            st["f_vcnt"] = pad_tail(st["f_vcnt"], 1, F_pad, fill=1)
+            # Padded host_flows columns hold -1 and rank below every real flow
+            # in the host round-robin, so picks (and hence all sends) match the
+            # unpadded point exactly.
+            st["host_flows"] = pad_tail(st["host_flows"], 1, Fh_pad, fill=-1)
+            for s in seeds:
+                d = {**st, **_repad_seed(_draw_seed_inputs(plan, s), plan,
+                                         pads[i])}
+                for k in ("a_stale", "c_stale"):
+                    d[k] = pad_tail(d[k], 0, npk_pad)
+                for k in ("a_conv", "c_conv"):
+                    d[k] = pad_tail(_pad_epochs(d[k], E_pad), 1, npk_pad)
+                # OFAN stacks lead with the [stale, epoch...] axis: 1 + E.
+                for k in ("ofan_e_orders", "ofan_e_starts", "ofan_e_len",
+                          "ofan_a_orders", "ofan_a_starts", "ofan_a_len"):
+                    d[k] = _pad_epochs(d[k], 1 + E_pad)
+                elems.append(d)
+                spans.append((i, s))
 
-    # OFAN rotation orders are padded to the group-wide width; entries past
-    # a row's own table length are never indexed (pointers wrap modulo the
-    # per-group length operand).
-    for key in ("ofan_e_orders", "ofan_a_orders"):
-        for d, arr in zip(elems, pad_to_group_max([d[key] for d in elems])):
-            d[key] = arr
+        # OFAN rotation orders are padded to the group-wide width; entries past
+        # a row's own table length are never indexed (pointers wrap modulo the
+        # per-group length operand).
+        for key in ("ofan_e_orders", "ofan_a_orders"):
+            widths = pad_to_group_max([d[key] for d in elems])
+            for d, arr in zip(elems, widths):
+                d[key] = arr
 
-    stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *elems)
+        stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *elems)
 
-    n_batch = len(elems)
-    if n_shards == "auto":
-        n_shards = max(1, min(len(jax.devices()), n_batch))
-    n_shards = int(n_shards)
-    stacked = shard_pad(stacked, n_batch, n_shards)
+        n_batch = len(elems)
+        if n_shards == "auto":
+            n_shards = max(1, min(len(jax.devices()), n_batch))
+        n_shards = int(n_shards)
+        stacked = shard_pad(stacked, n_batch, n_shards)
 
-    static = dataclasses.replace(
-        plans[0].static, P=npk_pad, F=F_pad, Fh=Fh_pad,
-        n=tree_pad.n_hosts, h=tree_pad.half,
-        mid=tree_pad.queues_per_mid_layer,
-        n_edges=tree_pad.n_edge_switches, n_aggs=tree_pad.n_agg_switches,
-        n_pods=tree_pad.n_pods)
-    out = jax.tree_util.tree_map(
-        np.asarray, _run(static, stacked, batch="mega", n_shards=n_shards))
+        static = dataclasses.replace(
+            plans[0].static, P=npk_pad, F=F_pad, Fh=Fh_pad,
+            n=tree_pad.n_hosts, h=tree_pad.half,
+            mid=tree_pad.queues_per_mid_layer,
+            n_edges=tree_pad.n_edge_switches, n_aggs=tree_pad.n_agg_switches,
+            n_pods=tree_pad.n_pods)
+        fn = _compiled(static, _shapes(stacked), "mega", n_shards)
+    out = fetch(execute(fn, *(stacked[k] for k in _ARG_ORDER)))
 
     results = [dict() for _ in items]
-    for b, (i, s) in enumerate(spans):
-        out_b = jax.tree_util.tree_map(lambda x: x[b], out)
-        results[i][s] = _postprocess(out_b, items[i][3],
-                                     plans[i].wl.n_packets,
-                                     plans[i].wl.n_flows, probes)
+    with stage("post"):
+        for b, (i, s) in enumerate(spans):
+            out_b = jax.tree_util.tree_map(lambda x: x[b], out)
+            results[i][s] = _postprocess(out_b, items[i][3],
+                                         plans[i].wl.n_packets,
+                                         plans[i].wl.n_flows, probes)
     return [[results[i][s] for s in seeds]
             for i, (_, _, _, _, seeds, _, _, _) in enumerate(items)]
 
@@ -869,9 +875,12 @@ def _compiled(static: _Static, shapes: tuple, batch, n_shards: int):
     return jax.jit(fn)
 
 
+def _shapes(tables: dict) -> tuple:
+    return tuple(sorted((k, np.shape(v)) for k, v in tables.items()))
+
+
 def _run(static: _Static, tables: dict, batch=False, n_shards: int = 1):
-    shapes = tuple(sorted((k, np.asarray(v).shape) for k, v in tables.items()))
-    fn = _compiled(static, shapes, batch, int(n_shards))
+    fn = _compiled(static, _shapes(tables), batch, int(n_shards))
     return fn(*(jnp.asarray(tables[k]) for k in _ARG_ORDER))
 
 
@@ -969,7 +978,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         # padding-invariant like every other output.
         st0["q_probe"] = jnp.zeros((5, s.probe[1]), INT)
 
-    def step(st_in):
+    def slot(at, st_in):
         st = dict(st_in)
         t = st["t"]
         # Fault-epoch counters.  ``pe``: the *physical* epoch (whose links
@@ -986,6 +995,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         ric = jnp.maximum(cvg_i - 1, 0)  # index into converged epoch stacks
 
         # ---- 1. serve all queues -------------------------------------------
+        at("serve")
         qcnt = st["qcnt"]
         has = qcnt > 0
         headpos = st["qhead"]
@@ -994,6 +1004,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         st["qcnt"] = jnp.where(has, qcnt - 1, qcnt)
 
         # ---- 2. route popped packets ---------------------------------------
+        at("route")
         qids = jnp.arange(NQ)
         stg = jnp.clip(qids // mid, 0, 4)
         pk = popped
@@ -1017,6 +1028,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         nxt = jnp.where(valid, nxt, -1)
 
         # ---- 3. deliveries (stage-4 pops) ----------------------------------
+        at("deliver")
         deliv = valid & (nxt == -2)
         dt = t + prop_slots
         first_del = deliv & ~st["p_recv"][pkc]
@@ -1049,6 +1061,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             jnp.where(dn_ok, dn_pk, -1))
 
         # ---- 4. fabric moves ------------------------------------------------
+        at("move")
         mover = valid & (nxt >= 0)
         dslot = (t + prop_slots) % DELAY
         st["dl_pkt"] = st["dl_pkt"].at[dslot, :4 * mid].set(
@@ -1057,6 +1070,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             jnp.where(mover, nxt, 0)[:4 * mid])
 
         # ---- 5. host injection ----------------------------------------------
+        at("inject")
         inflight = st["f_sent"] - st["f_acked"] - st["f_lost"]
         if cfg.cca == "ideal":
             window_ok = jnp.ones((F,), bool)
@@ -1133,6 +1147,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             t, mode="drop")
 
         # ---- 6. edge port choice for injected packets -----------------------
+        at("edge_pick")
         # REPS / PLB label machinery
         draw_idx = (st["f_draw"][sfv] * 48271 + 12345) % rand_pool.shape[0]
         fresh_lab = rand_pool[draw_idx]
@@ -1254,6 +1269,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             jnp.where(do_send, inj_q, 0))
 
         # ---- 7. arrivals: agg uplink choice then enqueue ---------------------
+        at("agg_pick")
         arr_slot = t % DELAY
         apk = st["dl_pkt"][arr_slot]
         aq = st["dl_q"][arr_slot]
@@ -1334,6 +1350,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             aq = jnp.where(to_agg, OFF[1] + asw * h + c_fin, aq)
 
         # ---- 8. enqueue (drops, ECN, failure black-holing) -------------------
+        at("enqueue")
         if use_pallas:
             if not fuse_agg:
                 (st["qbuf"], qcnt2, enq_try, do_enq, occ_after,
@@ -1382,6 +1399,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         st["dl_pkt"] = st["dl_pkt"].at[arr_slot].set(-1)
 
         # ---- 9. ACK processing -----------------------------------------------
+        at("ack")
         ak = st["al_pkt"][(t + 1) % ADELAY]   # written ack_delay slots ago
         aok = ak >= 0
         akc = jnp.maximum(ak, 0)
@@ -1460,6 +1478,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
                 jnp.where(dec_sel, akf, F)].set(t, mode="drop")
 
         # ---- 10. timeouts -----------------------------------------------------
+        at("timeout")
         inflight2 = st["f_sent"] - st["f_acked"] - st["f_lost"]
         rto_fire = ((st["f_sent"] > 0) & (st["f_complete"] < 0)
                     & (inflight2 > 0)
@@ -1474,6 +1493,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             st["f_cwnd"] = jnp.where(rto_fire, 1.0, st["f_cwnd"])  # freeze
 
         # ---- 11. flow completion ----------------------------------------------
+        at("complete")
         if cfg.loss == "sack":
             done_now = (st["f_complete"] < 0) & (st["f_cum"] >= fsize)
         else:
@@ -1483,10 +1503,17 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         st["t"] = t + 1
         return st
 
+    def step(st_in):
+        # Each numbered stage's device operations carry its name (serve ...
+        # complete) under ``slot`` in their HLO metadata.
+        with scopes() as at:
+            return slot(at, st_in)
+
     def cond(st):
         return (st["f_complete"] < 0).any() & (st["t"] < max_slots)
 
-    final = jax.lax.while_loop(cond, step, st0)
+    with jax.named_scope("slot"):
+        final = jax.lax.while_loop(cond, step, st0)
     out = {
         "delivered_slot": final["p_deliv"],
         "flow_complete": final["f_complete"],

@@ -62,7 +62,7 @@ def dispatch_line(span: Dict, total: int) -> str:
         bits.append(f"slots={span['slots_run']}")
     if "wall_s" in span:
         bits.append(f"{span['wall_s']:.2f}s")
-    if "compile_s" in span:
+    if span.get("compile_s"):
         bits.append(f"(compile {span['compile_s']:.2f}s)")
     if span.get("cache") == "hit":
         bits.append("[cached]")

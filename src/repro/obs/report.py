@@ -161,8 +161,8 @@ def render_report(spans: List[Dict], records: Optional[List[Dict]] = None,
         for s in loop_disp:
             lines.append(
                 f"slot budget (dispatch #{s['dispatch']}): ran "
-                f"{s['slots_run']}/{s['slot_budget']} slots, per-row fill "
-                f"{s.get('slot_fill', 0):.1%}")
+                f"{s['slots_run']}/{s['slot_budget']} slots, row-slot "
+                f"fill {s.get('row_slot_fill', 0):.1%}")
 
     # ---- benchmark ratios (BENCH_sweep.json, --bench) ---------------------
     if bench:

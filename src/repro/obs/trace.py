@@ -10,16 +10,20 @@ campaign (tested in ``tests/test_obs.py`` via :func:`strip_timing`).
 Span kinds (the ``kind`` field):
 
 * ``"plan"``     -- one per campaign, before execution: grid size, dispatch
-  and compiled-shape counts, device count, probe spec.  Cost-mode plans
+  and compiled-shape counts, device count, probe spec, the seconds the
+  set-up and planning took (``plan_s``).  Cost-mode plans
   (``Campaign.planner="cost"``) additionally record the chosen bucket
   policy (``policy``, ``kmap``, ``pkt_exact``), its ``predicted`` cost
   breakdown (padded packet rows, fill, compile charge), the rejected
   ``alternatives``, and -- when calibrated via ``--plan-from-trace`` --
   the ``calibration`` source.
 * ``"dispatch"`` -- one per fused megabatch: member population, padding
-  ratios (packet rows, batch-row fill, loop slot budget), shard/device
-  fill, wall seconds, optional compile-vs-execute split, compile-cache
-  hit/miss.  Loop-engine dispatches additionally carry ``"impl"`` -- the
+  ratios (packet rows, batch-row fill, loop slot budget and the rows'
+  ``row_slot_fill``), shard/device fill, wall seconds, the host-stage split
+  of :mod:`~repro.obs.stages` (``prep_s``, ``execute_s``, ``fetch_s``,
+  ``post_s``, ``retry_s``, ``record_s``, ``compile_s``) and
+  its counters (``bytes_in``, ``bytes_out``, ``jsq_retries``),
+  compile-cache hit/miss.  Loop-engine dispatches additionally carry ``"impl"`` -- the
   *resolved* slot-step implementation (``"lax"`` or ``"pallas"``; an
   ``impl="auto"`` campaign records whichever the host selected), so perf
   trajectories can tell kernel runs from inline-lax runs.
@@ -63,6 +67,8 @@ TRACE_SCHEMA = 1
 # pure function of the campaign spec and the simulation results.
 TIMING_KEYS = frozenset({
     "wall_s", "compile_s", "execute_s", "emit_s",
+    # Stage seconds (repro.obs.stages); their byte counters stay.
+    "plan_s", "prep_s", "fetch_s", "post_s", "retry_s", "record_s",
     "cache", "cache_dir", "cache_entries_added",
     # Robustness fields: which attempt failed, with what error, after what
     # backoff is environment-dependent (a transient OOM needn't recur).

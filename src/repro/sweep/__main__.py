@@ -105,7 +105,7 @@ def cmd_run(args) -> int:
         compile_cache_dir=(False if args.no_compile_cache
                            else compile_cache.DEFAULT_DIR),
         trace=trace, log=SweepLogger(level),
-        timing_split=args.timing_split, profile_dir=args.profile,
+        profile_dir=args.profile,
         retry=args.retry, backoff_s=args.backoff, resume=resume,
         cost_params=_cost_params(args))
     store.close()
@@ -209,8 +209,9 @@ def main(argv=None) -> int:
                             "model (repro.sweep.costmodel)")
         p.add_argument("--plan-from-trace", metavar="TRACE",
                        help="calibrate the cost model's compile charge "
-                            "from a measured trace.jsonl (spans written "
-                            "under --timing-split); implies --plan cost")
+                            "from a measured trace.jsonl (its dispatch "
+                            "spans' execute_s and compile_s); implies "
+                            "--plan cost")
 
     p_run = sub.add_parser("run", help="execute a campaign")
     _spec_args(p_run)
@@ -228,9 +229,6 @@ def main(argv=None) -> int:
     p_run.add_argument("--probes", metavar="STRIDE[,SAMPLES]",
                        help="record per-layer queue-occupancy time series "
                             "(repro.obs.probes; default 256 samples)")
-    p_run.add_argument("--timing-split", action="store_true",
-                       help="dispatch twice to split compile vs execute "
-                            "wall time in the trace")
     p_run.add_argument("--profile", metavar="DIR",
                        help="write a jax.profiler trace to DIR")
     p_run.add_argument("--retry", type=int, default=0, metavar="N",

@@ -21,8 +21,8 @@ extra compiles explicitly, against the padding they save.
 
 ``compile_rows`` -- how many padded packet rows one fresh compile is worth
 -- is the one free parameter.  :meth:`CostParams.from_trace` calibrates it
-from a measured PR-6 trace (``--plan-from-trace``): dispatch spans written
-under ``timing_split`` carry ``compile_s``/``execute_s``, giving both the
+from a measured campaign trace (``--plan-from-trace``): every dispatch span
+carries ``compile_s``/``execute_s`` (``repro.obs.stages``), giving both the
 per-padded-row execute rate and the typical compile cost in seconds.
 
 Selection is deterministic given (campaign, calibration): candidates are
@@ -57,12 +57,13 @@ class CostParams:
     def from_trace(cls, path) -> "CostParams":
         """Calibrate ``compile_rows`` from a measured dispatch trace.
 
-        Uses the ``timing_split`` fields of dispatch spans: the summed
-        ``execute_s`` over summed ``pkt_rows_padded`` gives seconds per
-        padded packet row; the median ``compile_s`` over that rate is the
-        row-equivalent compile charge.  A trace without usable timing
-        spans falls back to the defaults (``source`` says so), so a
-        heuristic-run trace can always be fed back in.
+        Uses the stage fields of dispatch spans: the summed ``execute_s``
+        over summed ``pkt_rows_padded`` gives seconds per padded packet
+        row; the median nonzero ``compile_s`` over that rate is the
+        row-equivalent compile charge.  A trace without dispatch spans
+        that timed an execute and a compile (every dispatch a cache hit,
+        say) falls back to the defaults (``source`` says so), so any trace
+        can be fed back in.
         """
         from ..obs.trace import load_trace
         spans = load_trace(path)
@@ -73,7 +74,8 @@ class CostParams:
         rows = sum(int(s["pkt_rows_padded"]) for s in timed)
         exec_s = sum(float(s["execute_s"]) for s in timed)
         if not compiles or rows <= 0 or exec_s <= 0.0:
-            return cls(source=f"{path} (no timing_split spans; defaults)")
+            return cls(source=f"{path} (no timed dispatch spans; "
+                              f"defaults)")
         per_row_s = exec_s / rows
         median_compile_s = compiles[len(compiles) // 2]
         compile_rows = min(max(median_compile_s / per_row_s, 1.0), 1e12)

@@ -44,6 +44,7 @@ from ..core.retry import retry_call
 from ..faults import FaultSchedule
 from ..obs.log import SweepLogger, dispatch_line
 from ..obs.probes import probe_shape
+from ..obs.stages import collect, stage
 from ..obs.trace import TraceWriter
 from . import compile_cache
 from .planner import MegaBatch, SeedBatch, plan
@@ -155,10 +156,11 @@ def _fault_of(b: SeedBatch):
 
 def _run_fast_mega(mega: MegaBatch, campaign: Campaign, cache: _Cache):
     """One fused dispatch for all member batches; returns results per member."""
-    items = [(cache.tree(b.k), cache.workload(b.k, b.load, b.phase),
-              lbs.by_name(b.scheme), b.seeds,
-              cache.link_state(b.k, b.failure), _fault_of(b))
-             for b in mega.members]
+    with stage("prep"):
+        items = [(cache.tree(b.k), cache.workload(b.k, b.load, b.phase),
+                  lbs.by_name(b.scheme), b.seeds,
+                  cache.link_state(b.k, b.failure), _fault_of(b))
+                 for b in mega.members]
     n_shards = "auto" if campaign.shard == "auto" else 1
     return fastsim.simulate_megabatch(items, prop_slots=campaign.prop_slots,
                                       backend=campaign.backend,
@@ -175,14 +177,16 @@ def _run_loop_mega(mega: MegaBatch, campaign: Campaign, cache: _Cache):
     their reaction delays come from the schedule itself."""
     rho_opt = campaign.loop_options().get("rho", 1.0)
     items = []
-    for b in mega.members:
-        rho = (cache.rho_auto(b.k, b.load, b.failure, b.phase)
-               if rho_opt == "auto" else float(rho_opt))
-        items.append((cache.tree(b.k), cache.workload(b.k, b.load, b.phase),
-                      lbs.by_name(b.scheme),
-                      campaign.loop_config(rho, timing=b.timing),
-                      b.seeds, cache.link_state(b.k, b.failure),
-                      b.g_converge, _fault_of(b)))
+    with stage("prep"):
+        for b in mega.members:
+            rho = (cache.rho_auto(b.k, b.load, b.failure, b.phase)
+                   if rho_opt == "auto" else float(rho_opt))
+            items.append((cache.tree(b.k),
+                          cache.workload(b.k, b.load, b.phase),
+                          lbs.by_name(b.scheme),
+                          campaign.loop_config(rho, timing=b.timing),
+                          b.seeds, cache.link_state(b.k, b.failure),
+                          b.g_converge, _fault_of(b)))
     n_shards = "auto" if campaign.shard == "auto" else 1
     return loopsim.simulate_megabatch(items, npk_pad=mega.npk_pad,
                                       n_shards=n_shards, k_pad=mega.k_pad,
@@ -348,13 +352,35 @@ def _run_with_recovery(idx: int, mega: MegaBatch, campaign: Campaign,
     return per_member, spans
 
 
+def _record(mega: MegaBatch, per_member: list, secs: float, cache: _Cache,
+            store: ResultStore, full: Optional[Dict]) -> None:
+    """Append one dispatch's records to ``store`` (and its raw results to
+    ``full``, when kept), in plan order; points that failed terminally have
+    no record (their error span is their trace)."""
+    to_record = (loop_point_record if mega.engine == "loop"
+                 else point_record)
+    for batch, results in zip(mega.members, per_member):
+        cp = (cache.compiled_phases(batch.k, batch.load, batch.phase)
+              if batch.phase is not None else None)
+        for point, res in zip(batch.points(), results):
+            if res is None:
+                continue
+            store.append(to_record(point, res, phases=cp))
+            if full is not None:
+                full[point] = res
+        # Apportion the fused dispatch's wall time over members by their
+        # share of fused points, so per-scheme timing summaries stay
+        # meaningful.
+        store.timings.append((batch, secs * len(batch.seeds)
+                              / max(mega.n_points, 1)))
+
+
 def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
                  keep_full: bool = False,
                  progress: Optional[Callable[[str], None]] = None,
                  compile_cache_dir: Optional[str] = None,
                  trace: Optional[TraceWriter] = None,
                  log: Optional[SweepLogger] = None,
-                 timing_split: bool = False,
                  profile_dir: Optional[str] = None,
                  retry: int = 0, backoff_s: float = 0.5,
                  sleep: Callable[[float], None] = time.sleep,
@@ -377,13 +403,17 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
 
     * ``trace`` -- a :class:`~repro.obs.trace.TraceWriter`; the runner emits
       one plan span, one span per fused dispatch and one campaign bookend.
+      Each dispatch span splits its seconds into the host stages of
+      :mod:`repro.obs.stages` (``prep_s``, ``execute_s``, ``fetch_s``,
+      ``post_s``, ``retry_s``, ``record_s``) plus
+      ``compile_s``, and counts ``bytes_in``, ``bytes_out`` and
+      ``jsq_retries``; the plan span carries ``plan_s``.  The same stages
+      annotate the profiler's timeline as ``sweep.<stage>`` spans inside
+      one ``sweep.dispatch`` span per dispatch.
     * ``log`` -- a :class:`~repro.obs.log.SweepLogger`; defaults to quiet
       when neither ``log`` nor ``progress`` is given.  The legacy
       ``progress`` callable maps to a debug-level logger with ``progress``
       as its sink, reproducing the old per-member output verbatim.
-    * ``timing_split`` -- dispatch twice (second call hits the in-process
-      compile caches and returns identical results) and report
-      ``compile_s`` / ``execute_s`` separately in the trace.
     * ``profile_dir`` -- wrap execution in ``jax.profiler.trace`` for
       TensorBoard-grade timelines (a backend without a profiler raises).
 
@@ -412,17 +442,19 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
     if log is None:
         log = (SweepLogger("debug", sink=progress) if progress is not None
                else SweepLogger("quiet"))
-    if compile_cache_dir is False:
-        compile_cache.disable()
-        cache_dir = None
-    else:
-        cache_dir = compile_cache.enable(compile_cache_dir)
-    import jax
-    devices = len(jax.devices())
-    p = plan(campaign, cost_params=cost_params)
-    log.info(p.describe())
-    if cache_dir:
-        log.info(f"persistent compile cache: {cache_dir}")
+    with collect() as planning, stage("plan"):
+        if compile_cache_dir is False:
+            compile_cache.disable()
+            cache_dir = None
+        else:
+            cache_dir = compile_cache.enable(compile_cache_dir)
+        import jax
+        devices = len(jax.devices())
+        p = plan(campaign, cost_params=cost_params)
+        log.info(p.describe())
+        if cache_dir:
+            log.info(f"persistent compile cache: {cache_dir}")
+        cache_files0 = _cache_files(cache_dir)
     if trace:
         span = {
             "kind": "plan", "campaign": campaign.name,
@@ -431,6 +463,7 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
             "engine": campaign.engine, "shard": campaign.shard,
             "probes": _probe_field(campaign),
             "cache_dir": str(cache_dir) if cache_dir else None,
+            "plan_s": planning["plan_s"],
         }
         if any(ph is not None for ph in campaign.phases):
             span["phases"] = [ph.label() if ph is not None else None
@@ -492,68 +525,53 @@ def run_campaign(campaign: Campaign, store: Optional[ResultStore] = None,
     prof = (jax.profiler.trace(str(profile_dir)) if profile_dir
             else contextlib.nullcontext())
 
-    cache_files0 = _cache_files(cache_dir)
     real_rows = padded_rows = 0     # realized padded-row fill this run
     t0 = time.perf_counter()
     with prof:
         for idx, mega in enumerate(p.megabatches):
             if idx < done:          # resume: records already on disk
                 continue
-            span = _dispatch_span(idx, mega, campaign, campaign.shard,
-                                  devices)
-            real_rows += span["pkt_rows_real"]
-            padded_rows += span["pkt_rows_padded"]
-            run = (_run_loop_mega if mega.engine == "loop"
-                   else _run_fast_mega)
-            to_record = (loop_point_record if mega.engine == "loop"
-                         else point_record)
-            misses0 = _compile_misses()
-            tb = time.perf_counter()
-            per_member, rspans = _run_with_recovery(
-                idx, mega, campaign, cache, run, retry=retry,
-                backoff_s=backoff_s, sleep=sleep, log=log)
-            t1 = time.perf_counter()
-            span["wall_s"] = secs = t1 - tb
-            span["cache"] = ("hit" if _compile_misses() == misses0
-                             else "miss")
-            if timing_split and not rspans:
-                # Second dispatch hits the in-process compile caches, so its
-                # wall time is pure execute; the first call's excess is the
-                # compile (+trace) cost.  Results are identical by the
-                # megabatch determinism contract.
-                per_member = run(mega, campaign, cache)
-                t2 = time.perf_counter()
-                span["execute_s"] = t2 - t1
-                span["compile_s"] = max(0.0, (t1 - tb) - (t2 - t1))
-            if mega.engine == "loop":
-                slots = [float(r.cct_acked_slots)
-                         for results in per_member for r in results
-                         if r is not None]
-                span["slots_run"] = int(max(slots)) if slots else 0
-                span["slot_fill"] = (span["slots_run"]
-                                     / max(span["slot_budget"], 1))
-            if trace:
-                for s in rspans:    # retry/error/degrade, in event order
-                    trace.emit(s)
-                trace.emit(span)
-            log.info(dispatch_line(span, p.n_dispatches))
-            for batch, results in zip(mega.members, per_member):
-                cp = (cache.compiled_phases(batch.k, batch.load, batch.phase)
-                      if batch.phase is not None else None)
-                for point, res in zip(batch.points(), results):
-                    if res is None:     # terminal failure: error span only
-                        continue
-                    store.append(to_record(point, res, phases=cp))
-                    if keep_full:
-                        full[point] = res
-                # Apportion the fused dispatch's wall time over members by
-                # their share of fused points, so per-scheme timing summaries
-                # stay meaningful.
-                store.timings.append((batch, secs * len(batch.seeds)
-                                      / max(mega.n_points, 1)))
-                log.debug(f"  {batch.scheme:>16s} k={batch.k} "
-                          f"{batch.load.label():<22s} x{len(batch.seeds)} "
-                          f"seeds: {store.timings[-1][1]:.2f}s")
+            with jax.profiler.TraceAnnotation("sweep.dispatch",
+                                              campaign=campaign.name,
+                                              dispatch=idx):
+                span = _dispatch_span(idx, mega, campaign, campaign.shard,
+                                      devices)
+                real_rows += span["pkt_rows_real"]
+                padded_rows += span["pkt_rows_padded"]
+                run = (_run_loop_mega if mega.engine == "loop"
+                       else _run_fast_mega)
+                misses0 = _compile_misses()
+                with collect() as stages:
+                    tb = time.perf_counter()
+                    per_member, rspans = _run_with_recovery(
+                        idx, mega, campaign, cache, run, retry=retry,
+                        backoff_s=backoff_s, sleep=sleep, log=log)
+                    span["wall_s"] = secs = time.perf_counter() - tb
+                    with stage("record"):
+                        _record(mega, per_member, secs, cache, store,
+                                full if keep_full else None)
+                span.update(stages)
+                span["cache"] = ("hit" if _compile_misses() == misses0
+                                 else "miss")
+                if mega.engine == "loop":
+                    acked = [float(r.cct_acked_slots)
+                             for results in per_member for r in results
+                             if r is not None]
+                    span["slots_run"] = int(max(acked)) if acked else 0
+                    # Share of the fused row-slots that did work: each
+                    # row's own ACK-complete slot over rows x slots run.
+                    span["row_slot_fill"] = (
+                        sum(acked) / max(mega.n_points * span["slots_run"],
+                                         1))
+                if trace:
+                    for s in rspans:    # retry/error/degrade, event order
+                        trace.emit(s)
+                    trace.emit(span)
+                log.info(dispatch_line(span, p.n_dispatches))
+                for batch, t in store.timings[-len(mega.members):]:
+                    log.debug(f"  {batch.scheme:>16s} k={batch.k} "
+                              f"{batch.load.label():<22s} "
+                              f"x{len(batch.seeds)} seeds: {t:.2f}s")
     wall = time.perf_counter() - t0
     if trace:
         trace.emit({
