@@ -735,8 +735,9 @@ def simulate_megabatch(items, *, npk_pad: Optional[int] = None,
                 st[k] = pad_tail(st[k], 0, E_pad, fill=2**30)
             # Flow-axis padding: pad flows have fsize 0, so they complete at
             # the first slot, never send, and never reference a packet;
-            # pkt_base is edge-padded so searchsorted still lands real
-            # packets on real flows.
+            # pkt_base is edge-padded, so every pad flow's base equals the
+            # real packet count and the engine's packet->flow table
+            # (packet_flows) still maps each real packet to its real flow.
             st["pkt_base"] = pad_tail(st["pkt_base"], 0, F_pad + 1,
                                       fill=int(st["pkt_base"][-1]))
             for k in _F_PAD0:
@@ -884,6 +885,15 @@ def _run(static: _Static, tables: dict, batch=False, n_shards: int = 1):
     return fn(*(jnp.asarray(tables[k]) for k in _ARG_ORDER))
 
 
+def packet_flows(pkt_base, P: int):
+    """Flow of every packet id in ``[0, P)``: the last flow whose first
+    packet id (``pkt_base``, ascending, one entry per flow plus the end)
+    is at most the id.  Built once per dispatch, so the slot body maps a
+    packet to its flow with one gather instead of a binary search."""
+    ids = jnp.arange(P, dtype=INT)
+    return (jnp.searchsorted(pkt_base, ids, side="right") - 1).astype(INT)
+
+
 def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
             f_start, f_inter, f_leaves, host_flows, alive, ep_start, r_start,
             e_ports, e_pcnt, a_ports, a_pcnt, e_dead, a_dead,
@@ -919,6 +929,8 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         use_pallas = _slot.resolve_impl(cfg.impl) == "pallas"
     OFF = (0, mid, 2 * mid, 3 * mid, 4 * mid)
     PBASE = pkt_base[:F]
+    with jax.named_scope("pkt_flow"):
+        pkt_flow = packet_flows(pkt_base, P)
     # JSQ guard for tree-size padding: +1e9 on port columns >= h_log (the
     # all-zero no-op when this point runs unpadded).
     pad_pen = port_pad_penalty(h, h_log)
@@ -1010,9 +1022,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         pk = popped
         valid = pk >= 0
         pkc = jnp.maximum(pk, 0)
-        pf = jnp.where(valid,
-                       jnp.searchsorted(pkt_base, pk, side="right") - 1,
-                       0).astype(INT)
+        pf = jnp.where(valid, pkt_flow[pkc], 0)
         a_ch = st["p_a"][pkc]
         c_ch = st["p_c"][pkc]
         p2 = fp2[pf]
@@ -1275,9 +1285,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         aq = st["dl_q"][arr_slot]
         avalid = apk >= 0
         apkc = jnp.maximum(apk, 0)
-        af = jnp.where(avalid,
-                       jnp.searchsorted(pkt_base, apk, "right") - 1,
-                       0).astype(INT)
+        af = jnp.where(avalid, pkt_flow[apkc], 0)
         to_agg = avalid & (aq >= OFF[1]) & (aq < OFF[2])
         asw = jnp.clip((aq - OFF[1]) // h, 0, s.n_aggs - 1).astype(INT)
         gpa = asw * s.n_pods + fp2[af]
@@ -1403,8 +1411,7 @@ def _engine(s: _Static, *, fsrc, fdst, fsize, pkt_base, fp1, fe1, fp2, fe2,
         ak = st["al_pkt"][(t + 1) % ADELAY]   # written ack_delay slots ago
         aok = ak >= 0
         akc = jnp.maximum(ak, 0)
-        akf = jnp.where(aok, jnp.searchsorted(pkt_base, ak, "right") - 1,
-                        0).astype(INT)
+        akf = jnp.where(aok, pkt_flow[akc], 0)
         st["al_pkt"] = st["al_pkt"].at[(t + 1) % ADELAY].set(-1)
         st["h_ackdebt"] = st["h_ackdebt"].at[
             jnp.where(aok, fsrc[akf], n)].add(cfg.ack_cost, mode="drop")

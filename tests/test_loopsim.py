@@ -1,5 +1,8 @@
 """Slotted feedback engine: cross-validation against fastsim, transport
 behavior (SACK/erasure/MSwift), and failure handling."""
+import dataclasses
+
+import jax
 import numpy as np
 import pytest
 
@@ -265,3 +268,94 @@ def test_mixed_zero_flows_inert(tree):
     ref = loopsim.simulate(tree, dense, lbs.host_pkt(), cfg, seed=0)
     np.testing.assert_array_equal(res.delivered_slot, ref.delivered_slot)
     assert res.cct_slots == ref.cct_slots
+
+
+# ---- packet->flow table ------------------------------------------------------
+
+def _mixed_zero_workload(tree):
+    fsize = np.array([3, 0, 2, 0, 1, 4, 0, 2])
+    src = np.arange(8)
+    dst = (np.arange(8) + 3) % tree.n_hosts
+    return workloads._packets_from_flows("mix", tree.n_hosts, src, dst, fsize)
+
+
+@pytest.mark.parametrize("layout", ("serial", "megabatch"))
+def test_packet_flow_table_matches_search(tree, wl, monkeypatch, layout):
+    """The engine's packet->flow table gives, for every id of the padded
+    packet axis, what a binary search of pkt_base gives, and the real flow
+    of every real packet: zero-size flows (duplicate bases), an edge-padded
+    pkt_base and a packet axis padded past the real packet count."""
+    mixed = _mixed_zero_workload(tree)
+    cfg = _CFGS["sack"]
+    if layout == "serial":
+        plan = loopsim._prepare(tree, mixed, lbs.host_pkt(), cfg)
+        bases = plan.tables["pkt_base"][None]
+        P, members = plan.static.P, [mixed]
+    else:
+        seen = {}
+        real = loopsim.execute
+
+        def spy(fn, *args):             # the dispatch's stacked operands
+            seen.update(zip(loopsim._ARG_ORDER, args))
+            return real(fn, *args)
+
+        monkeypatch.setattr(loopsim, "execute", spy)
+        loopsim.simulate_megabatch(
+            [(tree, mixed, lbs.host_pkt(), cfg, [0], None, None),
+             (tree, wl, lbs.host_dr(), cfg, [0], None, None)], npk_pad=1024)
+        bases, P = seen["pkt_base"], seen["a_stale"].shape[-1]
+        members = [mixed, wl]
+        # The padding this test is about: the mixed row's pkt_base is
+        # edge-padded to the wider workload's flow count, and the packet
+        # axis runs past both rows' real packets.
+        assert bases.shape[1] > mixed.n_flows + 1
+        assert P > max(w.n_packets for w in members)
+    assert (np.diff(bases[0]) == 0).any()     # zero-size flows share bases
+    ids = np.arange(P)
+    for pkt_base, w in zip(bases, members):
+        table = np.asarray(loopsim.packet_flows(pkt_base, P))
+        assert table.shape == (P,)
+        np.testing.assert_array_equal(
+            table, np.searchsorted(pkt_base, ids, side="right") - 1)
+        np.testing.assert_array_equal(table[:w.n_packets], w.flow)
+
+
+# ---- slot body structure ------------------------------------------------------
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in (v if isinstance(v, (tuple, list)) else (v,)):
+            j = getattr(x, "jaxpr", x)
+            if hasattr(j, "eqns"):
+                yield j
+
+
+def _all_eqns(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for j in _sub_jaxprs(e):
+            yield from _all_eqns(j)
+
+
+@pytest.mark.parametrize("cfg_name", ("erasure", "sack", "mswift"))
+@pytest.mark.parametrize("scheme", ("host_pkt_ar", "switch_pkt_ar", "ofan"))
+def test_slot_body_holds_no_nested_loop(tree, wl, cfg_name, scheme):
+    """The slot loop's body runs straight through: no inner ``while`` or
+    ``scan`` (a binary search, or any library call that lowers to a loop,
+    would repeat its trips in every slot of every row)."""
+    cfg = dataclasses.replace(_CFGS[cfg_name], impl="lax")
+    plan = loopsim._prepare(tree, wl, lbs.by_name(scheme), cfg)
+    tables = {**plan.tables, **loopsim._draw_seed_inputs(plan, 0)}
+    closed = jax.make_jaxpr(
+        lambda *a: loopsim._engine(plan.static,
+                                   **dict(zip(loopsim._ARG_ORDER, a))))(
+        *(tables[k] for k in loopsim._ARG_ORDER))
+    whiles = [e for e in _all_eqns(closed.jaxpr)
+              if e.primitive.name == "while"]
+    assert whiles
+    slot = max(whiles, key=lambda e: len(e.params["body_jaxpr"].jaxpr.eqns))
+    inner = [e.primitive.name
+             for part in ("cond_jaxpr", "body_jaxpr")
+             for e in _all_eqns(slot.params[part].jaxpr)
+             if e.primitive.name in ("while", "scan")]
+    assert inner == []
