@@ -1,5 +1,6 @@
-"""Plain reference of the simulated deployment's inputs, shared by both
-engine references: fat-tree coordinates, the permutation traffic matrix, the
+"""Plain reference of the simulated deployment's inputs, shared by the
+reference modules: fat-tree coordinates, the permutation traffic matrix and
+the failed links of a grid point from the names the runner records, the
 per-seed draws of each load-balancing scheme, and the Threefry-2x32 counter
 stream the switches draw their tie-break noise from.
 
@@ -80,6 +81,27 @@ def permutation(tree: Tree, msg: int, rng_seed: int) -> Traffic:
         if (perm != np.arange(n)).all():
             break
     return Traffic(np.arange(n), perm, msg)
+
+
+# ---------------------------------------------------------------------------
+# A grid point as the runner records it: ``workload`` is the load's label,
+# which ends in ``-r<traffic-matrix seed>``; ``failure`` is the failure
+# pattern's label, ``fail<p_fail>-r<pattern seed>``, or None.
+# ---------------------------------------------------------------------------
+
+def traffic_seed(rec: dict) -> int:
+    """The traffic-matrix seed of the grid point ``rec``."""
+    return int(rec["workload"].rsplit("-r", 1)[1])
+
+
+def permutation_point(config: dict, traffic: dict, rec: dict):
+    """(tree, traffic matrix) of the grid point ``rec``: the configuration's
+    fat tree and the traffic file's one load, a permutation."""
+    tree = Tree(config["k"])
+    (load,) = traffic["loads"]
+    if load["kind"] != "permutation":
+        raise ValueError("the reference covers permutation traffic")
+    return tree, permutation(tree, load["msg_packets"], traffic_seed(rec))
 
 
 def host_labels(scheme: str, tree: Tree, tr: Traffic, rng, alive=None):
@@ -210,6 +232,16 @@ def random_failures(tree: Tree, p_fail: float, seed: int) -> Links:
     u_ac = uniform(seed, SITE_LINK_FAIL, ids, 1, k)
     return Links((u_ea >= p_fail).reshape(k, h, h),
                  (u_ac >= p_fail).reshape(k, h, h))
+
+
+def point_failure(tree: Tree, traffic: dict, rec: dict) -> Links | None:
+    """The failed links of the pattern the grid point ``rec`` names, one of
+    the traffic file's ``failures``; None where every link is up."""
+    if rec["failure"] is None:
+        return None
+    (f,) = [f for f in traffic["failures"] if f is not None
+            and rec["failure"] == f"fail{f['p_fail']:g}-r{f['rng_seed']}"]
+    return random_failures(tree, f["p_fail"], f["rng_seed"])
 
 
 def paths(tree: Tree, links: Links, src: int, dst: int) -> np.ndarray:

@@ -18,6 +18,12 @@ arrival).  All links are up.
 ``dtype`` is the precision of the arrival and departure times.  The model
 states float32; this reference computes in float64 and the control in
 bfloat16.
+
+The program computes its times in float32 by a rearranged scan, so its
+records are compared by the widest absolute gap, in two classes of fields
+with a limit each: the per-layer averages and ratios (mean queue seen on
+arrival, overload), which are of order one, and every other field, a time
+or a queue maximum (slots or packets, hundreds of them).
 """
 from __future__ import annotations
 
@@ -28,6 +34,20 @@ from . import common
 HOST_LABEL = ("flow_ecmp", "subflow_mptcp", "host_pkt", "host_dr")
 SCHEMES = HOST_LABEL + ("ofan",)
 LAYERS = ("E_A", "A_C", "C_A", "A_E", "E_H")
+MEAN_PREFIXES = ("avg_wait_", "overload_")
+COMPARE = (
+    ("fast_mean_gap", "widest", lambda f: f.startswith(MEAN_PREFIXES)),
+    ("fast_time_gap", "widest", lambda f: True))
+
+
+def point(config: dict, traffic: dict, rec: dict, dtype=None) -> dict:
+    """The reference's record of the grid point ``rec`` names."""
+    if rec["failure"] is not None:
+        raise ValueError("the fast reference covers all links up")
+    tree, tr = common.permutation_point(config, traffic, rec)
+    kw = {} if dtype is None else {"dtype": dtype}
+    return simulate(tree, tr, rec["scheme"], rec["seed"],
+                    prop=config["prop_slots"], **kw)
 
 
 def _fifo(qid, arrive, tie, n_queues, dtype):

@@ -33,6 +33,10 @@ schedule of the alive ones (OFAN).
 ``dtype`` is the precision of the model's real-valued state (host credit,
 ACK debt, switch scores).  The model states float32; the control runs the
 same reference in bfloat16.
+
+The model's state is integer apart from that float32 state, which this
+reference computes in the same precision, so the program's records are
+compared exactly: every field of ``FIELDS``, the fields that differ counted.
 """
 from __future__ import annotations
 
@@ -45,6 +49,29 @@ from . import common
 HOST_LABEL = ("host_pkt", "host_dr", "host_pkt_ar")
 SCHEMES = HOST_LABEL + ("switch_pkt_ar", "ofan")
 QUANTA = (0.05, 0.10, 0.20)
+FIELDS = ("cct", "cct_acked", "max_queue", "avg_queue", "drops",
+          "retransmissions", "finished")
+COMPARE = (("loop_field_mismatches", "exact", FIELDS.__contains__),)
+
+
+def point(config: dict, traffic: dict, rec: dict, dtype=None) -> dict:
+    """The reference's record of the grid point ``rec`` names, at the
+    traffic file's rate (``"auto"``: the failure pattern's ``rho_max``)."""
+    o = config["loop_opts"]
+    if o.get("loss") != "sack":
+        raise ValueError("the loop reference covers SACK loss recovery")
+    tree, tr = common.permutation_point(config, traffic, rec)
+    links = common.point_failure(tree, traffic, rec)
+    rho = traffic.get("rho", 1.0)
+    if rho == "auto":
+        rho = 1.0 if links is None else common.rho_max(tree, links, tr)
+    kw = {} if dtype is None else {"dtype": dtype}
+    return simulate(
+        tree, tr, rec["scheme"], rec["seed"], prop=config["prop_slots"],
+        ack_delay=o["ack_delay"], buffer_pkts=o["buffer_pkts"],
+        sack_thresh=o["sack_thresh"], rto_slots=o["rto_slots"],
+        ack_cost=o["ack_cost"], rho=rho, max_slots=config["max_slots"],
+        links=links, g_converge=rec.get("g_converge"), **kw)
 
 
 def simulate(tree: common.Tree, tr: common.Traffic, scheme: str, seed: int,

@@ -3,7 +3,8 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration file
-(``bench/configs``: fabric, engine, loss recovery, the comparison's limit)
+(``bench/configs``: fabric, engine, loss recovery, the reference module
+and the comparison's limits)
 and a traffic file (``bench/traffic``: the campaign grid).  The harness
 drives ``repro.sweep.run_campaign``, the code behind ``python -m repro.sweep
 run``, as one closed-loop caller: one warm-up campaign (set-up), then
@@ -57,6 +58,8 @@ def load_cell(name: str, spec: dict | None = None):
     cell = cells[name]
     conf = next(c for c in spec["configs"] if c["name"] == cell["config"])
     config = json.loads((ROOT / conf["file"]).read_text())
+    import check
+    check.module(config)    # its reference module, before any set-up
     traffic = json.loads(
         (BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
 

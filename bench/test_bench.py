@@ -7,10 +7,18 @@
 * the replicate-seed pool: every run seed does the same work in another
   order;
 * the comparison that decides ``correct``: the program passes it, the
-  control (the reference in bfloat16 in the program's place) fails it;
+  control (the reference in bfloat16 in the program's place) fails it, in
+  every cell of ``BENCHMARK.json``;
+* the reference module a configuration names is found by that name: a
+  module ``check.py`` has never seen decides ``correct`` once a
+  configuration names it, and a configuration naming none fails;
 * a whole run with the timed path broken underneath fails it, once for
-  each fault a one-chip cell can have, and once more for a fault in the
-  fast engine's queue accounting alone.
+  each fault a one-chip cell can have in every cell whose campaign fuses
+  more than one point, and once more for a fault in the fast engine's
+  queue accounting alone in every cell whose limits hold the mean gap.
+
+The cells come from ``BENCHMARK.json`` as the tests are collected, so a
+cell that a later change adds is tested with no edit here.
 
 ``bench/data/fast-point-ofan.json`` holds the events of the first 20 ms
 of a traced ``fast-point-ofan`` window on a TPU v5 lite: the output of
@@ -22,6 +30,7 @@ from __future__ import annotations
 import copy
 import json
 import pathlib
+import sys
 
 import numpy as np
 import pytest
@@ -29,9 +38,18 @@ import pytest
 import check
 import devtrace
 import run
+from grid import Grid
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 SEED = 2**31 + 11
+SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in SPEC["workloads"]]
+
+
+def cells(keep):
+    """The cells whose configuration and traffic ``keep(config, traffic)``
+    accepts."""
+    return [c for c in CELLS if keep(*run.load_cell(c)[1:3])]
 
 
 def small(cell: str):
@@ -42,11 +60,39 @@ def small(cell: str):
     return config, traffic, e2e, per_layer
 
 
-def measure(cell: str, seconds=1.0):
-    config, traffic, e2e, per_layer = small(cell)
-    return run.measure(config, traffic, seed=SEED, seconds=seconds,
-                       trace=False, end_to_end=e2e, per_layer=per_layer,
-                       rehearse=True, log=lambda s: None)
+def measure(cell: str, seconds=1.0, config=None):
+    small_config, traffic, e2e, per_layer = small(cell)
+    return run.measure(config or small_config, traffic, seed=SEED,
+                       seconds=seconds, trace=False, end_to_end=e2e,
+                       per_layer=per_layer, rehearse=True,
+                       log=lambda s: None)
+
+
+def grid_records(config: dict, traffic: dict):
+    """Records of the cell's own grid points, three campaigns of one
+    replicate seed each, each campaign from its own run seed (so its own
+    traffic matrix, where the mix pins none), named as the runner names
+    them."""
+    out = []
+    for i, x in enumerate((3, 2**31 + 5, 77)):
+        campaign = Grid(config, traffic, SEED + i).campaign((x,))
+        out += [{"scheme": p.scheme, "seed": p.seed,
+                 "workload": p.load.label(),
+                 "failure": p.failure.label() if p.failure else None,
+                 "g_converge": p.g_converge} for p in campaign.points()]
+    return out
+
+
+def control_fails(config: dict, traffic: dict) -> bool:
+    """Whether the control, standing in for the program's record of each
+    grid point, fails one of the comparison's limits."""
+    import ml_dtypes
+    checks = check.compare(
+        config, traffic, grid_records(config, traffic), SEED,
+        stand_in=lambda r: check.reference(config, traffic, r,
+                                           dtype=ml_dtypes.bfloat16),
+        log=lambda s: None)
+    return any(op == "<=" and not v <= lim for _, v, op, lim in checks[1:])
 
 
 # ---------------------------------------------------------------------------
@@ -110,9 +156,9 @@ def test_trace_overhead_compares_busy_with_untraced_wall():
 # The replicate-seed pool
 # ---------------------------------------------------------------------------
 
-def test_seed_pool_is_the_same_work_in_another_order():
-    from grid import Grid
-    _, config, traffic, _, _ = run.load_cell("loop-fig5-fail")
+@pytest.mark.parametrize("cell", cells(lambda c, t: "seed_pool" in t))
+def test_seed_pool_is_the_same_work_in_another_order(cell):
+    _, config, traffic, _, _ = run.load_cell(cell)
     n = traffic["seed_pool"]
 
     def window_seeds(seed, campaigns):
@@ -131,30 +177,50 @@ def test_seed_pool_is_the_same_work_in_another_order():
 # The comparison and its control
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("cell", ["loop-fig12-sack", "loop-fig5-fail",
-                                  "fast-table2-perm", "fast-point-ofan"])
+@pytest.mark.parametrize("cell", CELLS)
 def test_program_passes_and_control_fails(cell):
-    import ml_dtypes
     out = measure(cell)
     assert out["correct"], out["checks"]
+    assert control_fails(*small(cell)[:2])
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
+def test_a_new_reference_module_is_found_by_name(monkeypatch, tmp_path,
+                                                 name):
+    """A cell brings its reference as a new file: a copy of the
+    configuration's reference module under a name ``check.py`` never saw,
+    in a directory of the ``ref`` package, is used once the configuration
+    names it, and the module it was copied from is not."""
+    import ref
+    cell = next(w["name"] for w in SPEC["workloads"] if w["config"] == name)
     config, traffic, _, _ = small(cell)
-    # Grid points named as the program's records name them; the control
-    # stands in for the program's record of each.
-    fails = [None if f is None else f"fail{f['p_fail']:g}-r{f['rng_seed']}"
-             for f in traffic.get("failures", [None])]
-    records = [{"scheme": s, "seed": int(x), "failure": f,
-                "g_converge": traffic.get("g_converge", [None])[0],
-                "workload": f"permutation-m256-r{17 + i}"}
-               for i, x in enumerate((3, 2**31 + 5, 77))
-               for s in traffic["schemes"] for f in fails]
-    checks = check.compare(
-        config, traffic, records, SEED,
-        stand_in=lambda r: check.reference(config, traffic, r,
-                                           dtype=ml_dtypes.bfloat16),
-        log=lambda s: None)
-    failing = [(n, v, lim) for n, v, op, lim in checks[1:]
-               if op == "<=" and not v <= lim]
-    assert failing, checks
+    own = check.module(config)
+    new = f"copy_of_{config['reference']}"
+    path = tmp_path / f"{new}.py"
+    path.write_text(pathlib.Path(own.__file__).read_text())
+    monkeypatch.setattr(ref, "__path__", [*ref.__path__, str(tmp_path)])
+
+    def unused(*args, **kwargs):
+        raise AssertionError(f"{own.__name__} compared a point")
+    monkeypatch.setattr(own, "point", unused)
+    config = dict(config, reference=new)
+    try:
+        out = measure(cell, seconds=0.5, config=config)
+        assert out["correct"], out["checks"]
+        assert control_fails(config, traffic)
+        assert pathlib.Path(check.module(config).__file__) == path
+    finally:
+        sys.modules.pop(f"ref.{new}", None)
+
+
+@pytest.mark.parametrize("reference", [None, "no_such_module"])
+def test_configuration_without_its_reference_module_fails(reference):
+    config, _, _, _ = small(CELLS[0])
+    config.pop("reference")
+    if reference:
+        config["reference"] = reference
+    with pytest.raises(ValueError, match="reference"):
+        check.module(config)
 
 
 # ---------------------------------------------------------------------------
@@ -241,12 +307,11 @@ def _occupancy_off_by_one(monkeypatch, engine):
 
 @pytest.mark.parametrize("fault", [_unchanged_state, _half_batch,
                                    _altered_answer])
-@pytest.mark.parametrize("cell", ["loop-fig12-sack", "loop-fig5-fail",
-                                  "fast-table2-perm"])
+@pytest.mark.parametrize("cell", cells(
+    lambda c, t: Grid(c, t, SEED).points_per_campaign > 1))
 def test_fault_in_timed_path_is_not_correct(monkeypatch, cell, fault):
     from repro.net import fastsim, loopsim
-    engine = "loop" if cell.startswith("loop") else "fast"
-    fault(monkeypatch, engine)
+    fault(monkeypatch, small(cell)[0]["engine"])
     try:
         out = measure(cell, seconds=0.5)
     finally:
@@ -256,7 +321,8 @@ def test_fault_in_timed_path_is_not_correct(monkeypatch, cell, fault):
     assert not out["correct"], out["checks"]
 
 
-@pytest.mark.parametrize("cell", ["fast-table2-perm", "fast-point-ofan"])
+@pytest.mark.parametrize("cell", cells(
+    lambda c, t: "fast_mean_gap" in c["check"]["limits"]))
 def test_queue_accounting_fault_is_caught_by_the_mean_gap(monkeypatch, cell):
     from repro.net import fastsim
     _occupancy_off_by_one(monkeypatch, "fast")
